@@ -88,12 +88,12 @@ def run(rows):
     ))
 
     # overlapped two-level FFT vs the staged blockfft at Hyena training
-    # lengths (ISSUE 9 acceptance rows).  Narrow D keeps the CPU run cheap;
-    # the schedule comparison is per-channel so the ratio transfers.  On
-    # CPU blockfft_overlap degrades to the identical blockfft math — the
-    # rows exist to pin the artifact shape; the overlap win itself (HBM
-    # streaming hidden behind the inner-DFT matmuls inside one
-    # pallas_call) is only measurable on TPU.
+    # lengths.  Narrow D keeps the CPU run cheap; the schedule comparison
+    # is per-channel so the ratio transfers.  On CPU blockfft_overlap runs
+    # its kernel body in the Pallas interpreter — the rows exist to pin
+    # the artifact shape; the overlap win itself (HBM streaming hidden
+    # behind the inner-DFT matmuls inside one pallas_call) is only
+    # measurable on TPU.
     from repro.core.conv_api import get_conv_backend
 
     bf = get_conv_backend("blockfft")
@@ -119,7 +119,7 @@ def run(rows):
         "kernels/conv_twolevel_overlap_accounting", 0.0,
         "pipelined_stages=inner_fft,pointwise,outer_combine;"
         "hbm_roundtrips_staged=5;hbm_roundtrips_overlapped=1;"
-        "plan_kind=twolevel;cpu=degrades_to_blockfft;"
+        "plan_kind=twolevel;cpu=pallas_interpret;"
         "measured_on=tpu_only",
     ))
 

@@ -37,7 +37,8 @@ buffer-assignment peak; ``temp_size_in_bytes``); every row also carries
 comparable only — rerun on TPU for real numbers, like the other BENCH
 artifacts.
 
-    PYTHONPATH=src python benchmarks/bench_train.py --json BENCH_train.json
+    JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/bench_train.py \
+        --json BENCH_train.json
 """
 from __future__ import annotations
 
@@ -51,7 +52,8 @@ import time
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8,
-                    help="forced host device count (cp axis size)")
+                    help="cp axis size; with JAX_PLATFORMS=cpu, the forced "
+                         "host device count")
     ap.add_argument("--tokens-per-chip", type=int, default=2048)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--d-model", type=int, default=64)
@@ -69,22 +71,31 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH")
     args = ap.parse_args()
 
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count={args.devices}",
-    )
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        # the CPU record runs the cp mesh on virtual host devices; on an
+        # accelerator the mesh is made of the real chips
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}",
+        )
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.common.policy import FP32
     from repro.configs.base import ModelConfig
+    from repro.launch.mesh import make_mesh
     from repro.train import optim as O
     from repro.train.trainer import (
         TrainConfig, init_train_state, make_train_step,
     )
 
     P_sz = args.devices
+    if len(jax.devices()) < P_sz:
+        raise SystemExit(
+            f"--devices {P_sz} needs {P_sz} devices; "
+            f"{jax.default_backend()} has {len(jax.devices())}"
+        )
     pattern = tuple(args.pattern.split(","))
     cfg = ModelConfig(
         name="bench-cp", family="bench",
@@ -153,7 +164,7 @@ def main() -> None:
     except Exception as e:  # pragma: no cover
         errors.append(f"train/cp1: {e!r}")
     try:
-        mesh = jax.make_mesh((1, P_sz), ("data", "model"))
+        mesh = make_mesh((1, P_sz), ("data", "model"))
         cp = dataclasses.replace(base, cp_axis="model")
         rows.append(run_case("train/cpP", cp, Lbig, mesh=mesh))
     except Exception as e:  # pragma: no cover
@@ -236,6 +247,8 @@ def main() -> None:
             json.dump(artifact, f, indent=1, sort_keys=True)
             f.write("\n")
         print(f"wrote {args.json} ({len(rows)} rows)")
+    if errors:
+        raise SystemExit("bench_train rows failed:\n" + "\n".join(errors))
 
 
 if __name__ == "__main__":

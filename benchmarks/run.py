@@ -22,6 +22,8 @@ import json
 import sys
 import traceback
 
+from repro.common import compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -31,6 +33,7 @@ def main() -> None:
         help="also write rows to PATH as a BENCH_*.json artifact",
     )
     args = ap.parse_args()
+    compile_cache.enable()
 
     from benchmarks import (
         bench_kernels,
@@ -105,6 +108,9 @@ def main() -> None:
             json.dump(artifact, f, indent=1, sort_keys=True)
             f.write("\n")
         print(f"wrote {args.json} ({len(rows)} rows)", file=sys.stderr)
+    if errors:
+        sys.exit(f"{len(errors)} bench module(s) failed: "
+                 f"{', '.join(e['module'] for e in errors)}")
 
 
 if __name__ == "__main__":

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import compile_cache
 from repro.common.param import split_params
 from repro.models.vit import ViTConfig, init_vit, vit_loss
 from repro.train import optim as O
@@ -19,6 +20,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = ViTConfig(image_size=16, patch_size=4, d_model=48, n_layers=2,
                     d_ff=96, n_classes=2)

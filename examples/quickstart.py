@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import compile_cache
 from repro.configs import get_config
 from repro.data import lm_data, tokenizer
 from repro.models import lm
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = dataclasses.replace(
         get_config("hyena-153m").reduced(),

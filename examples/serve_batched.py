@@ -34,6 +34,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common import compile_cache
 from repro.common.param import split_params
 from repro.configs import get_config
 from repro.data import tokenizer
@@ -54,6 +55,7 @@ def main():
                     help="serve through the paged engine: block-paged "
                     "caches, radix prefix reuse, chunked prefill")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = dataclasses.replace(
         get_config(args.arch).reduced(),

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import compile_cache
 from repro.configs import get_config
 from repro.data import synthetic
 from repro.models import lm
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--compress", action="store_true",
                     help="int8 error-feedback gradient compression")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = dataclasses.replace(
         get_config("hyena-153m").reduced(),

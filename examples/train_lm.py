@@ -19,6 +19,7 @@ import os
 import jax
 import numpy as np
 
+from repro.common import compile_cache
 from repro.configs import get_config
 from repro.data import lm_data, tokenizer
 from repro.train import optim as O
@@ -55,6 +56,7 @@ def main():
                     help="int8 error-feedback compression of the gradient "
                          "all-reduce (cross-pod bandwidth)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if not args.full:

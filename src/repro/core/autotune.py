@@ -36,6 +36,7 @@ never searched over.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import tempfile
 import threading
@@ -50,6 +51,7 @@ ENV_FILE = "REPRO_AUTOTUNE_FILE"
 MODES = ("off", "search", "load")
 _DEFAULT_FILE = os.path.join("~", ".cache", "repro", "conv_plans.json")
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 # in-memory mirror of the plan file, keyed by resolved path (the env var can
 # change between calls — tests point it at tmp dirs); each entry carries the
@@ -155,16 +157,26 @@ def search(
     candidates: Iterable[Dict[str, Any]],
     run: Callable[..., Any],
 ) -> Optional[Dict[str, Any]]:
-    """Best-wall-clock candidate (min over iters); raising candidates are
-    skipped (e.g. a tile that doesn't divide the shape)."""
+    """Best-wall-clock candidate (min over iters).  A candidate that raises
+    (a tile that doesn't divide the shape, a block the compiler refuses)
+    is logged and skipped; if every candidate raises, the search raises
+    with the last error as its cause — a plan never silently degrades to
+    the defaults that were just refused."""
     best, best_t = None, float("inf")
+    failures = []
     for cand in candidates:
         try:
             t = _time_once(lambda: run(**cand))
-        except Exception:
+        except Exception as e:  # each candidate is an independent trial
+            _log.warning("autotune: skipping candidate %s: %r", cand, e)
+            failures.append(e)
             continue
         if t < best_t:
             best, best_t = dict(cand), t
+    if best is None and failures:
+        raise RuntimeError(
+            f"autotune: all {len(failures)} candidates failed"
+        ) from failures[-1]
     return best
 
 

@@ -185,7 +185,7 @@ def _blockfft_overlap(u, h, skip=None, gate=None):
     kw = {}
     if autotune.mode() != "off":
 
-        def run(factors=None, overlap=2, block_d=128):
+        def run(factors=None, overlap=2, block_d=None):
             import jax.numpy as jnp
 
             uu = jnp.ones(u.shape, u.dtype)
@@ -276,13 +276,12 @@ register_conv_backend(ConvBackend(
 ))
 register_conv_backend(ConvBackend(
     name="blockfft_overlap", tag="twolevel_overlap", fn=_blockfft_overlap,
-    supports_gate=True,
+    supports_gate=True, requires_pallas=True,
     description="overlapped two-level (inner R / outer S) FFT conv: one "
-    "Pallas call pipelines inner-block DFT accumulation against HBM "
-    "streaming and finalizes twiddle/outer-DFT/pointwise/inverse + the "
-    "fused gate in VMEM (kernels/twolevel_fft.py); (R,S)/overlap/block_d "
-    "autotunable as the 'twolevel' plan kind; off-TPU degrades to the "
-    "identical-math blockfft schedule.",
+    "Pallas call pipelines the inner-block DFTs against HBM streaming "
+    "and finalizes twiddle/outer-DFT/pointwise/inverse + the fused gate "
+    "in VMEM (kernels/twolevel_fft.py); (R,S)/overlap/block_d "
+    "autotunable as the 'twolevel' plan kind; interpret-mode off-TPU.",
 ))
 register_conv_backend(ConvBackend(
     name="toeplitz", tag="pallas_mxu", fn=_toeplitz, requires_pallas=True,

@@ -98,15 +98,9 @@ def shard(x: jax.Array, *axes) -> jax.Array:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """Version-compat ``shard_map``: ``jax.shard_map`` (new API, ``check_vma``)
-    when present, else ``jax.experimental.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """``jax.shard_map`` with the repo's one spelling of its replication
+    check (``check`` -> ``check_vma``)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check,
     )

@@ -1,11 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas lowering runs natively; everywhere else
-(this CPU container) kernels execute via ``interpret=True`` so the *same
-kernel body* is validated.  ``use_kernel=False`` (or platform == cpu inside
-jit-of-dryrun lowerings where interpret overhead matters) falls back to the
+Dispatch policy (:func:`repro.kernels.platform.resolve_use_kernel`): on
+TPU the Pallas lowering always runs natively.  Off-TPU the default is the
 pure-jnp oracle in :mod:`repro.kernels.ref` — bit-compatible semantics by
-construction (tested).
+construction (tested) — and ``use_kernel=True`` runs the *same kernel body*
+in the Pallas interpreter.
 
 Tile sizes come from the autotuned conv-plan cache (:mod:`repro.core.
 autotune`, ``$REPRO_AUTOTUNE``): this module is the consultation point, so
@@ -22,7 +21,7 @@ from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import short_conv as _sc
 from repro.kernels import toeplitz_conv as _tc
-from repro.kernels.platform import on_tpu as _on_tpu
+from repro.kernels.platform import resolve_use_kernel
 
 
 def _dedup(cands):
@@ -55,10 +54,12 @@ def _short_conv_plan(shape, dtype, K: int, gated: bool):
 
 def _toeplitz_plan(shape, dtype, gated: bool, n_chunk_diags):
     B, L, D = shape
+    # chunks are whole 128-lane tiles; the (block_d, C, 2C) fp32 tap
+    # window bounds block_d by the scoped VMEM
     cands = _dedup(
-        {"chunk": min(c, L), "block_d": min(bd, D)}
-        for c in (64, 128, 256)
-        for bd in (128, 256)
+        {"chunk": c, "block_d": min(bd, D)}
+        for c in (128, 256)
+        for bd in (8, 16, 32)
     )
 
     def run(**tiles):
@@ -78,7 +79,7 @@ def _toeplitz_plan(shape, dtype, gated: bool, n_chunk_diags):
 
 
 def short_conv_gate(u, w, gate=None, *, use_kernel: bool | None = None, **kw):
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
+    use_kernel = resolve_use_kernel(use_kernel)
     if use_kernel:
         # mode() guard first: with autotune off (the default) the hot path
         # must not pay for candidate construction on every dispatch
@@ -95,7 +96,7 @@ def short_conv_gate(u, w, gate=None, *, use_kernel: bool | None = None, **kw):
 
 def toeplitz_conv(u, h, skip=None, gate=None, *,
                   use_kernel: bool | None = None, **kw):
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
+    use_kernel = resolve_use_kernel(use_kernel)
     if use_kernel:
         if (autotune.mode() != "off"
                 and "chunk" not in kw and "block_d" not in kw):
@@ -111,7 +112,7 @@ def toeplitz_conv(u, h, skip=None, gate=None, *,
 
 
 def flash_attention(q, k, v, *, use_kernel: bool | None = None, **kw):
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
+    use_kernel = resolve_use_kernel(use_kernel)
     if use_kernel:
         return _fa.flash_attention(q, k, v, **kw)
     kw.pop("blk_q", None), kw.pop("blk_k", None)
@@ -119,7 +120,7 @@ def flash_attention(q, k, v, *, use_kernel: bool | None = None, **kw):
 
 
 def rmsnorm(x, g, *, use_kernel: bool | None = None, **kw):
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
+    use_kernel = resolve_use_kernel(use_kernel)
     if use_kernel:
         return _rn.rmsnorm(x, g, **kw)
     return _ref.rmsnorm(x, g, eps=kw.get("eps", 1e-6))

@@ -48,29 +48,29 @@ def _toeplitz_kernel(
 
     @pl.when(r == 0)
     def _init():
-        ui = ui_ref[...].astype(jnp.float32)  # (B, C, blk_d), u chunk i
-        skip = skip_ref[0].astype(jnp.float32)  # (blk_d,)
-        acc_ref[...] = ui.transpose(2, 1, 0) * skip[:, None, None]
+        ui = ui_ref[...].astype(jnp.float32)  # (blk_d, B, C), u chunk i
+        acc_ref[...] = ui * skip_ref[...][:, :, None]
 
     @pl.when(r <= i)
     def _accumulate():
         taps = jnp.concatenate(
             [ha_ref[...], hb_ref[...]], axis=1
         ).astype(jnp.float32)  # (blk_d, 2C); padded coords kC .. kC+2C-1
-        a = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        b = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-        idx = C + a - b  # local tap index in [1, 2C-1]
-        T = jnp.take(taps, idx, axis=1)  # (blk_d, C, C)
-        u = u_ref[...].astype(jnp.float32)  # (B, C, blk_d), u chunk j
-        ut = u.transpose(2, 1, 0)  # (blk_d, C, B)
+        bd = taps.shape[0]
+        # W[d, s, t] = taps[d, C + t - s]: row s is the tap window shifted
+        # by C + s — one strided lane rotation (the MXU-native skew), then
+        # the first C lanes
+        win = jnp.broadcast_to(taps[:, None, :], (bd, C, 2 * C))
+        W = pltpu.roll(win, C, 2, stride=1, stride_axis=1)[:, :, :C]
+        u = u_ref[...].astype(jnp.float32)  # (blk_d, B, C), u chunk j
         acc_ref[...] += jax.lax.dot_general(
-            T, ut, (((2,), (1,)), ((0,), (0,))),
+            u, W, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(r == K - 1)
     def _finalize():
-        y = acc_ref[...].transpose(2, 1, 0).astype(o_ref.dtype)
+        y = acc_ref[...].astype(o_ref.dtype)
         if gated:
             # gate applied to the *downcast* accumulator, in VMEM: saves
             # the HBM round-trip of the two-pass schedule while staying
@@ -91,13 +91,16 @@ def toeplitz_conv(
     gate: jax.Array | None = None,  # (B, L, D) elementwise output gate
     *,
     chunk: int = 128,
-    block_d: int = 128,
+    block_d: int = 32,  # the (block_d, C, 2C) fp32 tap window: 4 MiB
     n_chunk_diags: int | None = None,
     interpret: bool | None = None,  # None => interpret off-TPU only
 ) -> jax.Array:
     interpret = resolve_interpret(interpret)
     B, L, D = u.shape
-    C = min(chunk, L)
+    # C stays whole even past L: the filter blocks are (block_d, C) lane
+    # tiles, so a short sequence pads up to one chunk (causality keeps the
+    # padded tail out of every real output)
+    C = chunk
     pad_l = (-L) % C
     block_d = min(block_d, D)
     pad_d = (-D) % block_d
@@ -113,11 +116,14 @@ def toeplitz_conv(
     Lp, Dp = u.shape[1], u.shape[2]
     n_chunks = Lp // C
     K = n_chunks if n_chunk_diags is None else min(n_chunk_diags, n_chunks)
+    # channel-major (Dp, B, Lp): each channel's Toeplitz matmul is a
+    # (B, C) @ (C, C) MXU tile with the sequence chunk on the lanes
+    ut = u.transpose(2, 0, 1)
     # front-pad C zeros => negative lags hit zeros (structural causality);
     # the last diagonal's high block needs one extra C of zeros at the end.
     hpad = jnp.pad(h, ((0, 0), (C, C)))  # (Dp, Lp + 2C)
     gated = gate is not None
-    g_in = gate if gated else jnp.zeros((B, 1, Dp), u.dtype)
+    g_in = gate.transpose(2, 0, 1) if gated else jnp.zeros((Dp, B, 1), u.dtype)
     grid = (Dp // block_d, n_chunks, K)
     out = pl.pallas_call(
         functools.partial(_toeplitz_kernel, C=C, K=K, gated=gated),
@@ -125,27 +131,28 @@ def toeplitz_conv(
         in_specs=[
             # u chunk j = i - r (clamped; masked when r > i)
             pl.BlockSpec(
-                (B, C, block_d),
-                lambda d, i, r: (0, jnp.maximum(i - r, 0), d),
+                (block_d, B, C),
+                lambda d, i, r: (d, 0, jnp.maximum(i - r, 0)),
             ),
             # filter window low/high blocks for lag-chunk k = r
             pl.BlockSpec((block_d, C), lambda d, i, r: (d, r)),
             pl.BlockSpec((block_d, C), lambda d, i, r: (d, r + 1)),
             # u chunk i (skip term, read at r == 0)
-            pl.BlockSpec((B, C, block_d), lambda d, i, r: (0, i, d)),
-            pl.BlockSpec((1, block_d), lambda d, i, r: (0, d)),
-            # gate chunk i (read at finalize; dummy row when ungated)
+            pl.BlockSpec((block_d, B, C), lambda d, i, r: (d, 0, i)),
+            pl.BlockSpec((block_d, 1), lambda d, i, r: (d, 0)),
+            # gate chunk i (read at finalize; dummy column when ungated)
             pl.BlockSpec(
-                (B, C if gated else 1, block_d),
-                (lambda d, i, r: (0, i, d)) if gated
-                else (lambda d, i, r: (0, 0, d)),
+                (block_d, B, C if gated else 1),
+                (lambda d, i, r: (d, 0, i)) if gated
+                else (lambda d, i, r: (d, 0, 0)),
             ),
         ],
-        out_specs=pl.BlockSpec((B, C, block_d), lambda d, i, r: (0, i, d)),
-        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        scratch_shapes=[pltpu.VMEM((block_d, C, B), jnp.float32)],
+        out_specs=pl.BlockSpec((block_d, B, C), lambda d, i, r: (d, 0, i)),
+        out_shape=jax.ShapeDtypeStruct(ut.shape, u.dtype),
+        scratch_shapes=[pltpu.VMEM((block_d, B, C), jnp.float32)],
         interpret=interpret,
-    )(u, hpad, hpad, u, skip.reshape(1, -1), g_in)
+    )(ut, hpad, hpad, ut, skip.astype(jnp.float32).reshape(-1, 1), g_in)
+    out = out.transpose(1, 2, 0)
     if pad_l or pad_d:
         out = out[:, :L, :D]
     return out

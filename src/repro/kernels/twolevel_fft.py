@@ -7,31 +7,34 @@ multiply, inverse) runs as a separate XLA op, so the activation makes a
 full HBM round-trip between stages.  This kernel runs the whole two-level
 schedule inside ONE ``pallas_call``:
 
-  * the grid iterates ``(channel_block, r_chunk)`` — Pallas's software
-    pipeline double-buffers the next chunk's HBM→VMEM streams (input slab,
-    DFT column block) against the current chunk's spectrum matmuls, so HBM
-    transfers overlap MXU compute;
-  * ``overlap`` is the pipeline depth: the inner-block DFT is split into
-    ``overlap`` accumulation chunks over the R rows (smaller in-flight
-    transfers, deeper overlap), accumulated into a VMEM spectrum scratch;
+  * every length-N signal (one per (channel, batch row)) is held as its
+    transposed four-step matrix ``At[s, r] = x[r·S + s]``, signals stacked
+    on the leading axis: the inner DFT is then one lane-contracting matmul
+    ``At @ FR`` and the outer DFT a per-signal left multiply ``FS @ Ut``,
+    with no in-kernel transpose;
+  * the grid iterates ``(channel_block, s_chunk)`` — Pallas's software
+    pipeline double-buffers the next s-chunk's HBM→VMEM input stream
+    against the current chunk's inner-DFT matmuls, so HBM transfers
+    overlap MXU compute;
+  * ``overlap`` is the pipeline depth: the input is streamed in
+    ``overlap`` chunks over the S rows (smaller in-flight transfers,
+    deeper overlap), each landing in its rows of a VMEM spectrum scratch;
   * on the last chunk the twiddle, outer S-point DFT, pointwise filter
     multiply, inverse transform, and the gated-fusion finalize (skip-add in
     fp32 → downcast → gate multiply in the output dtype, the DESIGN.md §7
-    bit-identity policy) all happen in VMEM — the conv output hits HBM
-    exactly once.
+    bit-identity policy) all happen in VMEM.  The wrapper's transposes to
+    and from the signal layout are plain XLA ops around the call.
 
 Complex arithmetic is carried as explicit (re, im) fp32 planes (Pallas TPU
 has no complex lanes); the filter spectrum is precomputed outside the
 kernel with the same factor split, so the kernel's pointwise stage matches
 ``blockfft_causal_conv``'s spectrum layout term for term.
 
-Off-TPU (CI) the same ``(R, S)`` schedule degrades to the plain
-``blockfft`` path — identical math, no interpret-mode timing theater; the
-kernel body itself is pinned by interpret-mode tests on small shapes
-(tests/test_conv_backends_prop.py).  The ``(R, S)`` split, channel tile,
-and overlap depth are autotunable as the ``"twolevel"`` plan kind
-(``core.autotune``; consulted by the ``blockfft_overlap`` registration in
-``core.conv_api``).
+Like every kernel here, off-TPU the body runs in the Pallas interpreter
+(tests pin it against the direct conv on small shapes); it never falls
+back to another schedule.  The ``(R, S)`` split, channel tile, and overlap
+depth are autotunable as the ``"twolevel"`` plan kind (``core.autotune``;
+consulted by the ``blockfft_overlap`` registration in ``core.conv_api``).
 """
 from __future__ import annotations
 
@@ -44,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockfft import _dft_mats, _factor, _four_step_fft
-from repro.kernels.platform import on_tpu
+from repro.kernels.platform import resolve_interpret
 
 
 def _largest_divisor_leq(n: int, k: int) -> int:
@@ -69,113 +72,138 @@ def twolevel_candidates(shape, limit: int = 3):
     cands = []
     for R, S in factor_candidates(N, limit=limit):
         for ov in (2, 4):
-            if R % ov:
+            if S % ov:
                 continue
-            for bd in (64, 128):
+            for bd in (8, 16):
                 cands.append(
                     {"factors": [R, S], "overlap": ov, "block_d": bd}
                 )
     # degenerate split vocabulary (tiny N): keep at least the default point
     if not cands:
         R, S = _factor(N)
-        cands.append({"factors": [R, S], "overlap": 1, "block_d": 128})
+        cands.append({"factors": [R, S], "overlap": 1, "block_d": 8})
     return cands
 
 
 def _twolevel_kernel(
-    u_ref,       # (B, Rc, S, bd) fp32 — r-chunk of the reshaped padded input
-    frre_c_ref,  # (R, Rc) inner DFT column block for this r-chunk (re)
-    frim_c_ref,  # (R, Rc) (im)
-    frre_ref,    # (R, R) full inner DFT — the inverse needs every column
-    frim_ref,    # (R, R)
-    twre_ref,    # (R, S) twiddle W_N^{k1 s} (re)
-    twim_ref,    # (R, S) (im)
-    fsre_ref,    # (S, S) outer DFT (re)
+    x_ref,       # (Q, Sc, R) fp32 — s-chunk of the transposed padded input
+    frre_ref,    # (R, R) inner DFT (re); symmetric, so FR == FR^T
+    frim_ref,    # (R, R) (im)
+    fsre_ref,    # (S, S) outer DFT (re); symmetric
     fsim_ref,    # (S, S) (im)
-    hre_ref,     # (R, S, bd) filter spectrum block (re)
-    him_ref,     # (R, S, bd) (im)
-    ui_ref,      # (B, L, bd) fp32 original input (skip term, finalize)
-    skip_ref,    # (1, bd) fp32
-    g_ref,       # (B, L, bd) gate (output dtype; dummy row when ungated)
-    o_ref,       # (B, L, bd) output
-    accre_ref, accim_ref,  # VMEM (B, R, S, bd) fp32 spectrum accumulators
-    *, N: int, L: int, overlap: int, gated: bool,
+    twre_ref,    # (S, R) transposed twiddle W_N^{k1 s} (re)
+    twim_ref,    # (S, R) (im)
+    hre_ref,     # (bd, S, R) transposed filter spectrum block (re)
+    him_ref,     # (bd, S, R) (im)
+    xi_ref,      # (Q, S, R) fp32 whole input block (skip term, finalize)
+    skip_ref,    # (bd, 1, 1) fp32
+    g_ref,       # (Q, S, R) gate (output dtype; dummy block when ungated)
+    o_ref,       # (Q, S, R) output
+    accre_ref, accim_ref,  # VMEM (Q, S, R) fp32 spectrum accumulators
+    *, N: int, overlap: int, gated: bool,
 ):
     c = pl.program_id(1)
+    Q, Sc, R = x_ref.shape
+    S = accre_ref.shape[1]
+    bd = hre_ref.shape[0]
+    f32 = jnp.float32
 
-    @pl.when(c == 0)
-    def _init():
-        accre_ref[...] = jnp.zeros_like(accre_ref)
-        accim_ref[...] = jnp.zeros_like(accim_ref)
+    def mm(a, b):  # (M, K) @ (K, N) on the MXU
+        return jnp.dot(a, b, preferred_element_type=f32)
 
-    # ---- stage 1 (every pipeline step): inner-DFT accumulation.  The
-    # next chunk's input slab / DFT column block stream HBM→VMEM while
-    # this chunk's matmuls occupy the MXU — the overlap this kernel
-    # exists for.  Real input, so a chunk costs two real matmuls.
-    a = u_ref[...]
-    accre_ref[...] += jnp.einsum(
-        "kr,brsd->bksd", frre_c_ref[...], a,
-        preferred_element_type=jnp.float32,
-    )
-    accim_ref[...] += jnp.einsum(
-        "kr,brsd->bksd", frim_c_ref[...], a,
-        preferred_element_type=jnp.float32,
-    )
+    def bmm(m, x):  # per-signal left multiply: (Q, S, S) @ (Q, S, R)
+        return jax.lax.dot_general(
+            m, x, (((2,), (1,)), ((0,), (0,))), preferred_element_type=f32,
+        )
+
+    # ---- stage 1 (every pipeline step): inner R-point DFTs of this
+    # s-chunk.  The next chunk streams HBM→VMEM while these matmuls
+    # occupy the MXU — the overlap this kernel exists for.  Real input,
+    # so a chunk costs two real matmuls: Bt = At @ FR.
+    a = x_ref[...].reshape(Q * Sc, R)
+    off = pl.multiple_of(c * Sc, Sc)
+    accre_ref[:, pl.ds(off, Sc), :] = mm(a, frre_ref[...]).reshape(Q, Sc, R)
+    accim_ref[:, pl.ds(off, Sc), :] = mm(a, frim_ref[...]).reshape(Q, Sc, R)
 
     # ---- stages 2–5 (last step only): twiddle → outer DFT → pointwise
     # filter → inverse transform → gated finalize, all in VMEM.
     @pl.when(c == overlap - 1)
     def _finalize():
-        # twiddle W_N^{k1 s} (elementwise complex multiply)
-        twre = twre_ref[...][None, :, :, None]
-        twim = twim_ref[...][None, :, :, None]
+        twre = twre_ref[...][None]
+        twim = twim_ref[...][None]
         bre, bim = accre_ref[...], accim_ref[...]
         ure = bre * twre - bim * twim
         uim = bre * twim + bim * twre
-        # outer S-point DFT: C[k1, j] = Σ_s U[k1, s] · FS[s, j]
-        fsre, fsim = fsre_ref[...], fsim_ref[...]
-        dot = functools.partial(
-            jnp.einsum, "bksd,sj->bkjd",
-            preferred_element_type=jnp.float32,
-        )
-        cre = dot(ure, fsre) - dot(uim, fsim)
-        cim = dot(ure, fsim) + dot(uim, fsre)
-        # pointwise filter multiply in the spectrum (same layout as
-        # blockfft._four_step_fft: X[k1 + k2·R] = C[k1, k2])
-        hre = hre_ref[...][None]
-        him = him_ref[...][None]
-        yre = cre * hre - cim * him
-        yim = cre * him + cim * hre
-        # inverse outer DFT: D[k1, s] = Σ_j Y[k1, j] · conj(FS)[s, j]
-        idot = functools.partial(
-            jnp.einsum, "bkjd,sj->bksd",
-            preferred_element_type=jnp.float32,
-        )
-        dre = idot(yre, fsre) + idot(yim, fsim)
-        dim = idot(yim, fsre) - idot(yre, fsim)
+        # outer S-point DFT of every signal: Ct = FS @ Ut
+        fsre = jnp.broadcast_to(fsre_ref[...][None], (Q, S, S))
+        fsim = jnp.broadcast_to(fsim_ref[...][None], (Q, S, S))
+        cre = bmm(fsre, ure) - bmm(fsim, uim)
+        cim = bmm(fsre, uim) + bmm(fsim, ure)
+        # pointwise filter multiply: one spectrum per channel, shared by
+        # the batch rows of that channel (signal q = d·B + b)
+        hre = hre_ref[...][:, None]
+        him = him_ref[...][:, None]
+        cre = cre.reshape(bd, Q // bd, S, R)
+        cim = cim.reshape(bd, Q // bd, S, R)
+        yre = (cre * hre - cim * him).reshape(Q, S, R)
+        yim = (cre * him + cim * hre).reshape(Q, S, R)
+        # inverse outer DFT: Dt = conj(FS) @ Yt
+        dre = bmm(fsre, yre) + bmm(fsim, yim)
+        dim = bmm(fsre, yim) - bmm(fsim, yre)
         # conjugate twiddle (elementwise)
         ere = dre * twre + dim * twim
         eim = dim * twre - dre * twim
-        # inverse inner DFT — conv output is real by construction, so only
-        # the real plane: Re A[r] = Σ_k (FRre[k,r]·Ere[k] + FRim[k,r]·Eim[k])
-        rdot = functools.partial(
-            jnp.einsum, "kr,bksd->brsd",
-            preferred_element_type=jnp.float32,
-        )
-        are = rdot(frre_ref[...], ere) + rdot(frim_ref[...], eim)
-        B = are.shape[0]
-        bd = are.shape[-1]
-        # (B, R, S, bd) row-major == x[r·S + s]: inverse of the forward
-        # reshape, so this is exactly the length-N time axis
-        y = are.reshape(B, N, bd)[:, :L, :] * (1.0 / N)
+        # inverse inner DFT — the conv output is real by construction, so
+        # only the real plane: Re At = Et_re @ FRre + Et_im @ FRim
+        are = (
+            mm(ere.reshape(Q * S, R), frre_ref[...])
+            + mm(eim.reshape(Q * S, R), frim_ref[...])
+        ).reshape(Q, S, R)
         # gated-fusion finalize (fftconv._fused_epilogue policy): skip-add
         # in fp32, downcast, THEN gate in the output dtype — bit-identical
         # to the two-pass gate-after schedule
-        y = y + ui_ref[...] * skip_ref[0][None, None, :]
+        skip = jnp.broadcast_to(
+            skip_ref[...][:, None], (bd, Q // bd, 1, 1)
+        ).reshape(Q, 1, 1)
+        y = are * (1.0 / N) + xi_ref[...] * skip
         y = y.astype(o_ref.dtype)
         if gated:
             y = y * g_ref[...].astype(o_ref.dtype)
         o_ref[...] = y
+
+
+def _to_signals(x, R: int, S: int):
+    """(B, N, Dp) -> (Dp·B, S, R): signal q = d·B + b, with
+    ``out[q, s, r] = x[b, r·S + s, d]`` (the four-step matrix, transposed
+    so both DFT stages contract over a lane or a leading axis)."""
+    B, N, Dp = x.shape
+    return x.reshape(B, R, S, Dp).transpose(3, 0, 2, 1).reshape(Dp * B, S, R)
+
+
+def _from_signals(y, B: int, R: int, S: int):
+    """Inverse of :func:`_to_signals`."""
+    Dp = y.shape[0] // B
+    return y.reshape(Dp, B, S, R).transpose(1, 3, 2, 0).reshape(B, R * S, Dp)
+
+
+# scoped-VMEM budget for one grid step: the finalize keeps ~a dozen
+# (Q, S, R) fp32 planes live, past the 16 MiB default at hyena widths
+_VMEM_LIMIT = 96 * 1024 * 1024
+# default channel tile: the largest divisor of D whose (Q, S, R) fp32
+# plane (lanes padded to 128) fits in _PLANE_BYTES, with at most
+# _MAX_SIGNALS signals — Mosaic unrolls the batched outer-DFT matmuls
+# over Q, so compile time grows with it
+_PLANE_BYTES = 1 << 20
+_MAX_SIGNALS = 64
+
+
+def _pipeline_depth(S: int, overlap: int) -> int:
+    """Largest depth <= ``overlap`` that splits S into whole 8-row sublane
+    tiles (what a TPU block must be); 1 streams all S rows at once."""
+    for ov in range(min(overlap, S), 1, -1):
+        if S % ov == 0 and (S // ov) % 8 == 0:
+            return ov
+    return 1
 
 
 @functools.partial(
@@ -189,103 +217,91 @@ def twolevel_fft_conv(
     gate: Optional[jax.Array] = None,  # (B, L, D) elementwise output gate
     *,
     factors: Optional[Tuple[int, int]] = None,  # autotuned (R, S) split
-    block_d: int = 128,
-    overlap: int = 2,  # inner-DFT pipeline depth (clamped to divide R)
-    interpret: bool | None = None,  # True forces the Pallas body (tests)
+    block_d: Optional[int] = None,  # None: sized to _PLANE_BYTES
+    overlap: int = 2,  # input pipeline depth (see _pipeline_depth)
+    interpret: bool | None = None,  # None => interpret off-TPU only
 ) -> jax.Array:
-    """Two-level overlapped FFT causal conv (ConvBackend contract).
-
-    On TPU (or with ``interpret=True``) runs the single-``pallas_call``
-    pipelined schedule; elsewhere degrades to ``blockfft_causal_conv``
-    with the same ``(R, S)`` split — identical math, so the CPU CI sweep
-    exercises the real schedule's numerics rather than interpret-mode
-    theater.
-    """
-    from repro.core.blockfft import blockfft_causal_conv
+    """Two-level overlapped FFT causal conv (ConvBackend contract): one
+    ``pallas_call`` over a ``(channel_block, s_chunk)`` grid."""
     from repro.core.fftconv import next_fast_len
 
+    interpret = resolve_interpret(interpret)
     B, L, D = u.shape
     N = next_fast_len(2 * L - 1)
     if factors is not None and factors[0] * factors[1] != N:
         factors = None  # stale plan for a different padded length
-    if not (on_tpu() or interpret):
-        return blockfft_causal_conv(u, h, skip, gate, factors=factors)
-
     R, S, FR, FS, TW = _dft_mats(N, factors)
-    ov = _largest_divisor_leq(R, overlap)
+    ov = _pipeline_depth(S, overlap)
+    Sc = S // ov
+    if block_d is None:
+        plane = B * S * (-(-R // 128) * 128) * 4  # one channel's signals
+        block_d = _largest_divisor_leq(
+            D, min(_PLANE_BYTES // plane, _MAX_SIGNALS // B)
+        )
     bd = max(1, min(block_d, D))
     pad_d = (-D) % bd
-    out_dtype = u.dtype
-    u32 = u.astype(jnp.float32)
-    h32 = h.astype(jnp.float32)
-    g_in = gate
-    if pad_d:
-        u32 = jnp.pad(u32, ((0, 0), (0, 0), (0, pad_d)))
-        h32 = jnp.pad(h32, ((0, pad_d), (0, 0)))
-        if g_in is not None:
-            g_in = jnp.pad(g_in, ((0, 0), (0, 0), (0, pad_d)))
     Dp = D + pad_d
+    Q = bd * B
+    out_dtype = u.dtype
+    pad = ((0, 0), (0, N - L), (0, pad_d))
+    xt = _to_signals(jnp.pad(u.astype(jnp.float32), pad), R, S)
+    h32 = jnp.pad(h.astype(jnp.float32), ((0, pad_d), (0, 0)))
     skip32 = (
         jnp.zeros((Dp,), jnp.float32) if skip is None
         else jnp.pad(skip.astype(jnp.float32), (0, pad_d))
     )
-    # padded input in the (B, R, S, D) four-step layout: x[r·S + s] = A[r, s]
-    up = jnp.pad(u32, ((0, 0), (0, N - L), (0, 0)))
-    u4 = up.reshape(B, R, S, Dp)
     # filter spectrum, precomputed with the SAME split (one small transform
-    # per call, shared across the batch and the grid)
+    # per call, shared across the batch and the grid), transposed to the
+    # signal layout: Ht[d, k2, k1] = X[k1 + k2·R]
     hp = jnp.pad(h32.T, ((0, N - L), (0, 0)))[None]  # (1, N, Dp)
-    H = _four_step_fft(hp, N, (R, S))[0]  # (R, S, Dp) complex64
-    gated = g_in is not None
-    g_arg = g_in if gated else jnp.zeros((B, 1, Dp), out_dtype)
-    Rc = R // ov
+    Ht = _four_step_fft(hp, N, (R, S))[0].transpose(2, 1, 0)  # (Dp, S, R)
+    gated = gate is not None
+    if gated:
+        g_arg = _to_signals(jnp.pad(gate.astype(out_dtype), pad), R, S)
+        g_spec = pl.BlockSpec((Q, S, R), lambda d, c: (d, 0, 0))
+    else:
+        g_arg = jnp.zeros((Dp * B, 8, R), out_dtype)
+        g_spec = pl.BlockSpec((Q, 8, R), lambda d, c: (d, 0, 0))
+    full = lambda d, c: (0, 0)  # noqa: E731 — block-pinned constants
+    sig = pl.BlockSpec((Q, S, R), lambda d, c: (d, 0, 0))
 
-    grid = (Dp // bd, ov)
     out = pl.pallas_call(
-        functools.partial(
-            _twolevel_kernel, N=N, L=L, overlap=ov, gated=gated,
-        ),
-        grid=grid,
+        functools.partial(_twolevel_kernel, N=N, overlap=ov, gated=gated),
+        grid=(Dp // bd, ov),
         in_specs=[
-            # r-chunk of the reshaped input (streams in per pipeline step)
-            pl.BlockSpec((B, Rc, S, bd), lambda d, c: (0, c, 0, d)),
-            # inner DFT column block for this r-chunk
-            pl.BlockSpec((R, Rc), lambda d, c: (0, c)),
-            pl.BlockSpec((R, Rc), lambda d, c: (0, c)),
-            # full inner DFT (the inverse at finalize needs every column)
-            pl.BlockSpec((R, R), lambda d, c: (0, 0)),
-            pl.BlockSpec((R, R), lambda d, c: (0, 0)),
-            # twiddle + outer DFT (whole matrices, block-pinned)
-            pl.BlockSpec((R, S), lambda d, c: (0, 0)),
-            pl.BlockSpec((R, S), lambda d, c: (0, 0)),
-            pl.BlockSpec((S, S), lambda d, c: (0, 0)),
-            pl.BlockSpec((S, S), lambda d, c: (0, 0)),
+            # s-chunk of the input (streams in per pipeline step)
+            pl.BlockSpec((Q, Sc, R), lambda d, c: (d, c, 0)),
+            pl.BlockSpec((R, R), full),
+            pl.BlockSpec((R, R), full),
+            pl.BlockSpec((S, S), full),
+            pl.BlockSpec((S, S), full),
+            pl.BlockSpec((S, R), full),
+            pl.BlockSpec((S, R), full),
             # filter spectrum block for this channel tile
-            pl.BlockSpec((R, S, bd), lambda d, c: (0, 0, d)),
-            pl.BlockSpec((R, S, bd), lambda d, c: (0, 0, d)),
-            # original input (skip term) + skip + gate, read at finalize
-            pl.BlockSpec((B, L, bd), lambda d, c: (0, 0, d)),
-            pl.BlockSpec((1, bd), lambda d, c: (0, d)),
-            pl.BlockSpec(
-                (B, L if gated else 1, bd), lambda d, c: (0, 0, d)
-            ),
+            pl.BlockSpec((bd, S, R), lambda d, c: (d, 0, 0)),
+            pl.BlockSpec((bd, S, R), lambda d, c: (d, 0, 0)),
+            # whole input block (skip term) + skip + gate, read at finalize
+            sig,
+            pl.BlockSpec((bd, 1, 1), lambda d, c: (d, 0, 0)),
+            g_spec,
         ],
-        out_specs=pl.BlockSpec((B, L, bd), lambda d, c: (0, 0, d)),
-        out_shape=jax.ShapeDtypeStruct((B, L, Dp), out_dtype),
+        out_specs=sig,
+        out_shape=jax.ShapeDtypeStruct((Dp * B, S, R), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((B, R, S, bd), jnp.float32),
-            pltpu.VMEM((B, R, S, bd), jnp.float32),
+            pltpu.VMEM((Q, S, R), jnp.float32),
+            pltpu.VMEM((Q, S, R), jnp.float32),
         ],
-        interpret=bool(interpret) if interpret is not None else False,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
     )(
-        u4,
+        xt,
         jnp.asarray(FR.real), jnp.asarray(FR.imag),
-        jnp.asarray(FR.real), jnp.asarray(FR.imag),
-        jnp.asarray(TW.real), jnp.asarray(TW.imag),
         jnp.asarray(FS.real), jnp.asarray(FS.imag),
-        jnp.asarray(H.real), jnp.asarray(H.imag),
-        u32[:, :L, :], skip32.reshape(1, -1), g_arg,
+        jnp.asarray(TW.real.T), jnp.asarray(TW.imag.T),
+        Ht.real, Ht.imag,
+        xt, skip32.reshape(Dp, 1, 1), g_arg,
     )
-    if pad_d:
-        out = out[:, :, :D]
-    return out
+    return _from_signals(out, B, R, S)[:, :L, :D]

@@ -1,4 +1,7 @@
 import os
+# CPU-only planning tool: the production meshes are 512 virtual host
+# devices, and the dry-run never claims an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # test hook: REPRO_DRYRUN_DEVICES overrides the placeholder-device count
 # (still before any jax import — jax locks the device count on first init).

@@ -1,5 +1,10 @@
-"""Production meshes. Defined as functions (never module-level constants)
-so importing this module never touches jax device state.
+"""Meshes. Defined as functions (never module-level constants) so importing
+this module never touches jax device state.
+
+Every mesh the program, its tests and its benchmarks build goes through
+:func:`make_mesh`, which gives each axis ``AxisType.Auto``: the model code
+steers layouts with ``with_sharding_constraint`` and lets GSPMD propagate
+the rest, which ``Explicit`` axes (the ``jax.make_mesh`` default) reject.
 
 Single pod: 16×16 = 256 chips (TPU v5e pod), axes ("data", "model").
 Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model") — the "pod"
@@ -8,18 +13,31 @@ shards over ("pod", "data") via the 'data' alias in repro.distributed.ctx.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with ``Auto`` axes over ``devices`` (default: all
+    of ``jax.devices()``)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for in-process distributed tests (host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def parse_mesh_arg(spec: str):

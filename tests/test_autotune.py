@@ -236,3 +236,24 @@ def test_bad_mode_raises(monkeypatch):
     monkeypatch.setenv(autotune.ENV_MODE, "always")
     with pytest.raises(ValueError, match="REPRO_AUTOTUNE"):
         autotune.mode()
+
+
+def test_search_skips_and_logs_failing_candidates(caplog):
+    def run(block):
+        if block == 7:
+            raise ValueError("block 7 does not divide the shape")
+        return jnp.ones(4)
+
+    with caplog.at_level("WARNING", logger="repro.core.autotune"):
+        best = autotune.search([{"block": 7}, {"block": 8}], run)
+    assert best == {"block": 8}
+    assert "skipping candidate {'block': 7}" in caplog.text
+
+
+def test_search_raises_when_every_candidate_fails():
+    def run(block):
+        raise ValueError(f"compiler refused block {block}")
+
+    with pytest.raises(RuntimeError, match="all 2 candidates failed") as e:
+        autotune.search([{"block": 7}, {"block": 9}], run)
+    assert "refused block 9" in str(e.value.__cause__)

@@ -153,7 +153,8 @@ def test_fft_sp_sharded_gated_parity_subprocess():
         from repro.core.conv_api import get_conv_backend
         from repro.distributed import ctx
 
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         fft_sp = get_conv_backend("fft_sp")
         fft = get_conv_backend("fft_local")
         B, L, D = 3, 64, 4
@@ -212,11 +213,11 @@ def test_toeplitz_pallas_gated_tail_blocks(B, L, D, C, bd):
 def test_blockfft_overlap_registered_with_contract():
     """The overlapped two-level conv is a first-class registry citizen:
     gate fused at the kernel's finalize (DESIGN.md §14), never the oracle,
-    and requires_pallas=False — off-TPU it degrades to the identical
-    blockfft math, so every CPU sweep above exercises the real registered
-    entry point."""
+    and requires_pallas=True — off-TPU the kernel body runs in the Pallas
+    interpreter (never another schedule), so every CPU sweep above
+    exercises the real registered kernel."""
     b = get_conv_backend("blockfft_overlap")
-    assert b.supports_gate and not b.oracle and not b.requires_pallas
+    assert b.supports_gate and not b.oracle and b.requires_pallas
     assert b.tag == "twolevel_overlap"
 
 

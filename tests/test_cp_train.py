@@ -42,11 +42,11 @@ def test_sp_conv_grad_parity_all_shapes():
     match the local reference to fp32 noise."""
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh
         from repro.distributed.spconv import sp_fft_causal_conv
         from repro.core.fftconv import fft_causal_conv
 
-        mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(0)
         B, L, D = 4, 64, 8
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
@@ -94,11 +94,11 @@ def test_mesh_conv_backends_grad_parity_vs_local():
     gate fusion must be bit-compatible in the backward too)."""
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh
         from repro.core import conv_api
         from repro.distributed.ctx import use_mesh
 
-        mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(3)
         B, L, D = 4, 60, 8   # non-divisible L: fft_sp pads internally
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
@@ -135,11 +135,11 @@ def test_cp_attention_grad_parity():
     forward and dq/dk/dv, full-causal and windowed (GQA shapes)."""
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh
         from repro.models.attention import (
             cp_ring_attention, cp_allgather_attention, chunked_attention)
 
-        mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(1)
         B, L, H, Hkv, Dh = 4, 64, 4, 2, 16
         kq, kk, kv, kd = jax.random.split(key, 4)
@@ -198,7 +198,8 @@ def test_cp_train_step_matches_single_device_per_mixer():
                 hyena_filter_width=16, hyena_pos_dim=9,
             )
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, L = 8, 32
         for mixer in ["hyena", "attention", "local_attention", "ssd"]:
             cfg = small_cfg(mixer)
@@ -269,7 +270,8 @@ def test_cp_train_step_multihybrid_se_mr_li_attn():
             hyena_filter_width=16, hyena_pos_dim=9,
             hyena_se_len=4, hyena_mr_support=8,
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, L = 8, 32
         tok = jax.random.randint(jax.random.PRNGKey(1), (B, L), 0, 64)
         lab = jax.random.randint(jax.random.PRNGKey(2), (B, L), 0, 64)
@@ -331,7 +333,8 @@ def test_cp_full_train_step_runs_and_composes():
             local_window=8, ssm_state=16, ssd_head_dim=16, rnn_width=32,
             hyena_filter_width=16, hyena_pos_dim=9,
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         tcfg = T.TrainConfig(
             optimizer=O.AdamWConfig(lr=1e-3, warmup_steps=0),
             remat=True, policy=FP32, cp_axis="model", microbatches=2)
@@ -367,7 +370,8 @@ def test_cp_shift_targets_matches_plain_shift():
         import jax, jax.numpy as jnp, numpy as np
         from repro.train.trainer import cp_shift_targets
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         tok = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0, 64)
         ref = cp_shift_targets(tok)  # plain concat shift
         got = jax.jit(lambda t: cp_shift_targets(t, mesh, "model"))(tok)
@@ -501,7 +505,8 @@ def test_cp_long_context_trains_where_unsharded_peak_is_larger():
             return p
 
         base = T.TrainConfig(optimizer=opt, remat=False, policy=FP32)
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 8), ("data", "model"))
         cp = dataclasses.replace(base, cp_axis="model")
         p_cp = peak(cp, mesh=mesh, execute=True)
         p_un = peak(base)  # lowered only — this is the one that OOMs for real

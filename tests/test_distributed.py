@@ -34,7 +34,9 @@ def test_param_sharding_rules_divisibility():
     from repro.distributed.sharding import resolve_spec
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     # mesh sizes are 1 here, so craft a fake mesh-shape via a real mesh of
     # the production shape is impossible in-process; use the rule engine's
@@ -98,7 +100,8 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = make_train_step(cfg, tcfg)(state, batch)
 
         # 2x4 mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         pshard = param_shardings(axes, state["params"], mesh, fsdp=True)
         state2, _ = init_train_state(jax.random.PRNGKey(0), cfg)
         state2 = {
@@ -136,7 +139,8 @@ def test_sp_fft_conv_matches_reference():
         from repro.core.fftconv import fft_causal_conv
         from repro.distributed.spconv import sp_fft_causal_conv
 
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         B, L, D = 2, 64, 4
         u = jax.random.normal(jax.random.PRNGKey(0), (B, L, D))
         h = jax.random.normal(jax.random.PRNGKey(1), (D, L)) / L
@@ -156,7 +160,8 @@ def test_pipeline_matches_sequential():
         from repro.distributed.pipeline import pipeline_forward
 
         S, T, mb, d = 4, 6, 3, 8
-        mesh = jax.make_mesh((4,), ("pipe",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pipe",))
         ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) / np.sqrt(d)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, mb, d))
 
@@ -190,7 +195,8 @@ def test_compressed_psum_accuracy():
         from repro.distributed.compression import compressed_psum
         from repro.distributed.ctx import shard_map
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def body(xb):
@@ -231,7 +237,8 @@ def test_compressed_train_step_on_mesh():
         assert "cgrad" in state
         s1, m1 = make_train_step(cfg, tcfg)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ectx = tcfg.apply_context(mesh=mesh)
         state2, _ = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
         shardings = ectx.train_state_shardings(axes, state2)

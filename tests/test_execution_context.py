@@ -112,7 +112,7 @@ def _FakeMesh():
     # AbstractMesh: NamedSharding-compatible without real devices
     from jax.sharding import AbstractMesh
 
-    return AbstractMesh((("data", 2), ("model", 2)))
+    return AbstractMesh((2, 2), ("data", "model"))
 
 
 def test_train_state_shardings_generalize_params_rules():
@@ -266,7 +266,8 @@ def test_fft_sp_prefill_routing_end_to_end():
         params, _ = split_params(lm.init_lm(jax.random.PRNGKey(0), cfg))
         prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0,
                                     cfg.vocab_size)
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         routed = ExecutionContext(mesh=mesh, sp_min_len=16)
         assert routed.conv_backend_for(16) == "fft_sp"
         lg1, _ = lm.prefill(params, cfg, prompt, 24, dtype=jnp.float32,

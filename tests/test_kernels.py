@@ -144,3 +144,21 @@ def test_flash_unpadded_vs_padded():
     got = fa.flash_attention(q, k, v, blk_q=32, blk_k=32, interpret=True)
     want = ref.flash_attention(q, k, v)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+# ----------------------------------------------------- platform dispatch
+
+def test_tpu_never_interprets_or_falls_back(monkeypatch):
+    """On a TPU the kernels lower natively and the ops wrappers never hand
+    the work to the jnp oracle; off-TPU both defaults pick the stand-ins."""
+    from repro.kernels import platform
+
+    assert platform.resolve_interpret(None) is True  # this CPU host
+    assert platform.resolve_use_kernel(None) is False
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    assert platform.resolve_interpret(None) is False
+    assert platform.resolve_use_kernel(None) is True
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        platform.resolve_interpret(True)
+    with pytest.raises(ValueError, match="use_kernel=False on a TPU"):
+        platform.resolve_use_kernel(False)

@@ -16,6 +16,7 @@ from repro.core.conv_api import (
     registered_conv_backends,
     resolve_conv_backend,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import blocks, lm
 from repro.models.mixer_api import (
     ApplyContext,
@@ -376,7 +377,7 @@ def test_ctx_mesh_override_matches_ambient():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                 cfg.vocab_size)
     want, _ = lm.forward(params, cfg, tokens)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     got, _ = lm.forward(params, cfg, tokens, ctx=ApplyContext(mesh=mesh))
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),

@@ -360,7 +360,8 @@ def test_reversible_cp_matches_single_device_per_mixer():
                 hyena_se_len=4, hyena_mr_support=8,
             )
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, L = 8, 32
         for mixer in {mixers!r}:
             cfg = small_cfg(mixer)
@@ -439,7 +440,8 @@ def test_reversible_cp8_multihybrid_and_moe():
                 moe=True, n_experts=4, top_k=2,
             ),
         }
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 8), ("data", "model"))
         B, L = 4, 64
         for name, cfg in cases.items():
             tok = jax.random.randint(jax.random.PRNGKey(1), (B, L), 0, 64)

@@ -353,7 +353,8 @@ tcfg = TrainConfig(optimizer=O.AdamWConfig(lr=1e-3, warmup_steps=0,
                    remat=False, grad_compression="int8_ef")
 lcfg = LoopConfig(total_steps={steps}, ckpt_dir={d_mesh!r}, ckpt_every=2,
                   log_every=99, heartbeat_interval=None)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 corpus = np.arange(20_000, dtype=np.int32) % 31
 stream = lm_data.TokenStream(corpus, global_batch=4, seq_len=32, seed=7,
                              cursor=0)
