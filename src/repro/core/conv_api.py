@@ -29,6 +29,8 @@ import dataclasses
 import os
 from typing import Callable, Dict, Optional
 
+import jax
+
 ENV_VAR = "REPRO_CONV_BACKEND"
 DEFAULT_BACKEND = "fft"
 
@@ -60,6 +62,7 @@ class ConvBackend:
                 f"got {L}"
             )
 
+    @jax.named_scope("long_conv")
     def __call__(self, u, h, skip=None, gate=None):
         if gate is None:
             return self.fn(u, h, skip)

@@ -165,6 +165,7 @@ def direct_causal_conv(
     return _fused_epilogue(y, u32, skip, gate, u.dtype)
 
 
+@jax.named_scope("short_conv")
 def short_causal_conv(
     u: jax.Array,  # (B, L, D)
     w: jax.Array,  # (D, K) short explicit filter (K ~ 3/4)

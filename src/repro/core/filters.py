@@ -90,6 +90,7 @@ def init_hyena_filter(key, cfg: FilterConfig) -> Dict[str, Any]:
     }
 
 
+@jax.named_scope("implicit_filter")
 def evaluate_filters(params: Dict[str, Any], cfg: FilterConfig, L: int) -> jax.Array:
     """h: (order, d_model, L) float32 — Algorithm 2 (parallel across N, L)."""
     z = positional_encoding(L, cfg.pos_dim)  # (L, De)
@@ -117,6 +118,7 @@ def evaluate_filters(params: Dict[str, Any], cfg: FilterConfig, L: int) -> jax.A
     return h
 
 
+@jax.named_scope("implicit_filter")
 def filter_skip(params: Dict[str, Any], cfg: FilterConfig) -> jax.Array:
     """Per-(order, D) skip gain, shape (order, D)."""
     return params["skip"].reshape(cfg.order, cfg.d_model)

@@ -85,13 +85,28 @@ def init_hyena(key, cfg: HyenaConfig) -> Dict[str, Any]:
     return params
 
 
-def _project(params, cfg: HyenaConfig, u: jax.Array):
-    """Algorithm 1: linear → short depthwise causal conv → split."""
-    B, L, D = u.shape
-    N = cfg.order
+@jax.named_scope("hyena_proj")
+def in_projection(params, u: jax.Array) -> jax.Array:
+    """D → (N+1)·D, with its bias when the operator has one."""
     z = u @ params["in_proj"]["w"].astype(u.dtype)
     if "b" in params["in_proj"]:
         z = z + params["in_proj"]["b"].astype(u.dtype)
+    return z
+
+
+@jax.named_scope("hyena_proj")
+def out_projection(params, v: jax.Array) -> jax.Array:
+    """D → D, with its bias when the operator has one."""
+    y = v @ params["out_proj"]["w"].astype(v.dtype)
+    if "b" in params["out_proj"]:
+        y = y + params["out_proj"]["b"].astype(v.dtype)
+    return y
+
+
+def _project(params, cfg: HyenaConfig, u: jax.Array):
+    """Algorithm 1: linear → short depthwise causal conv → split."""
+    N = cfg.order
+    z = in_projection(params, u)
     z = short_causal_conv(z, params["short_filter"])  # (B, L, (N+1)·D)
     parts = jnp.split(z, N + 1, axis=-1)
     v, xs = parts[0], parts[1:]
@@ -114,10 +129,7 @@ def hyena_operator(
     skip = F.filter_skip(params["filters"], cfg.filter)  # (N, D)
     for n in range(cfg.order):
         v = backend(v, h[n], skip[n], gate=xs[n]).astype(u.dtype)
-    y = v @ params["out_proj"]["w"].astype(u.dtype)
-    if "b" in params["out_proj"]:
-        y = y + params["out_proj"]["b"].astype(u.dtype)
-    return y
+    return out_projection(params, v)
 
 
 # ---------------------------------------------------------------------------
@@ -223,9 +235,7 @@ def hyena_decode_step(
     if h is None:
         h, skip = _fallback_decode_taps(params, cfg, Lc)
     # --- projection + short conv (explicit taps over a tiny rolling window)
-    z = u_t @ params["in_proj"]["w"].astype(u_t.dtype)
-    if "b" in params["in_proj"]:
-        z = z + params["in_proj"]["b"].astype(u_t.dtype)
+    z = in_projection(params, u_t)
     w = params["short_filter"]  # (inner, K)
     hist = cache["short"]  # (B, K-1, inner) newest-first
     zc = z.astype(jnp.float32) * w[:, 0].astype(jnp.float32)[None, :]
@@ -262,9 +272,7 @@ def hyena_decode_step(
         vs.append(v.astype(ldtype))
         conv_y = hist[n] + v.astype(jnp.float32) * h0[n][None, :]
         v = xs[n] * conv_y.astype(u_t.dtype)
-    y = v @ params["out_proj"]["w"].astype(u_t.dtype)
-    if "b" in params["out_proj"]:
-        y = y + params["out_proj"]["b"].astype(u_t.dtype)
+    y = out_projection(params, v)
     rows = jnp.arange(B)
     new_long = cache["long"].at[:, rows, t].set(jnp.stack(vs))
     out_cache = dict(cache)

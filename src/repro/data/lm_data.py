@@ -16,6 +16,7 @@ import queue
 import threading
 from typing import Dict, Iterator, Optional
 
+import jax
 import numpy as np
 
 
@@ -109,7 +110,10 @@ class Prefetcher:
                     continue
 
     def next(self):
-        batch, state = self._q.get()
+        # on the profiler's clock, beside the device ops: a gap in the
+        # device's work that falls inside this span waited on the loader
+        with jax.profiler.TraceAnnotation("repro.data.wait"):
+            batch, state = self._q.get()
         # state as of the *consumed* batch — checkpoint this (not the
         # stream's own cursor, which has run ahead by the prefetch depth)
         self.consumed_state = state

@@ -20,7 +20,9 @@ from repro.core.operator import (
     HyenaConfig,
     hyena_decode_step,
     init_decode_cache,
+    in_projection,
     init_hyena,
+    out_projection,
     precompute_decode_filters,
 )
 from repro.distributed.ctx import shard
@@ -51,9 +53,7 @@ def apply_hyena_mixer(
     N = cfg.order
     cp = getattr(ctx, "cp_axis", None)
     seq_axis = cp or "model"
-    z = x @ params["in_proj"]["w"].astype(x.dtype)
-    if "b" in params["in_proj"]:
-        z = z + params["in_proj"]["b"].astype(x.dtype)
+    z = in_projection(params, x)
     z = shard(z, "data", seq_axis, None)  # seq-sharded; short conv halo-exchanges
     z = short_causal_conv(z, params["short_filter"])
     parts = jnp.split(z, N + 1, axis=-1)
@@ -85,10 +85,7 @@ def apply_hyena_mixer(
         v = shard(v, "data", cp, None) if cp is not None else shard(
             v, "data", None, "model"
         )
-    y = v @ params["out_proj"]["w"].astype(x.dtype)
-    if "b" in params["out_proj"]:
-        y = y + params["out_proj"]["b"].astype(x.dtype)
-    return y
+    return out_projection(params, v)
 
 
 def init_hyena_cache(cfg: HyenaConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -116,9 +113,7 @@ def hyena_prefill(
     B, L, D = x.shape
     backend.validate_len(L)
     N = cfg.order
-    z_pre = x @ params["in_proj"]["w"].astype(x.dtype)
-    if "b" in params["in_proj"]:
-        z_pre = z_pre + params["in_proj"]["b"].astype(x.dtype)
+    z_pre = in_projection(params, x)
     z = short_causal_conv(z_pre, params["short_filter"])
     parts = jnp.split(z, N + 1, axis=-1)
     v, xs = parts[0], parts[1:]
@@ -147,9 +142,7 @@ def hyena_prefill(
     for n in range(N):
         longs.append(hist(v))
         v = backend(v, h_dec[n][:, :L], skip[n], gate=xs[n]).astype(x.dtype)
-    y = v @ params["out_proj"]["w"].astype(x.dtype)
-    if "b" in params["out_proj"]:
-        y = y + params["out_proj"]["b"].astype(x.dtype)
+    y = out_projection(params, v)
     cache = dict(cache)
     cache.update({
         "short": short_hist,

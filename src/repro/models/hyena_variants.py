@@ -45,7 +45,11 @@ from repro.common.param import Ax
 from repro.core import filters as F
 from repro.core.conv_api import get_conv_backend
 from repro.core.fftconv import short_causal_conv
-from repro.core.operator import _fallback_decode_taps
+from repro.core.operator import (
+    _fallback_decode_taps,
+    in_projection,
+    out_projection,
+)
 from repro.distributed.ctx import shard
 from repro.models.hyena import HyenaMixer
 from repro.models.mixer_api import (
@@ -129,9 +133,7 @@ def _init_projection(key, d_model: int, order: int, short_filter_len: int,
 def _project_seq_sharded(params, order: int, x: jax.Array, seq_axis):
     """Algorithm 1 under the residual-stream layout: linear (weights
     gathered), seq-sharded short conv (SPMD halo exchange), split."""
-    z = x @ params["in_proj"]["w"].astype(x.dtype)
-    if "b" in params["in_proj"]:
-        z = z + params["in_proj"]["b"].astype(x.dtype)
+    z = in_projection(params, x)
     z = shard(z, "data", seq_axis, None)
     z = short_causal_conv(z, params["short_filter"])
     parts = jnp.split(z, order + 1, axis=-1)
@@ -141,9 +143,7 @@ def _project_seq_sharded(params, order: int, x: jax.Array, seq_axis):
 def _decode_project(params, cfg, u_t, cache):
     """Decode-time Algorithm 1 over the tiny rolling short-conv window —
     the same math as ``operator.hyena_decode_step``'s projection block."""
-    z = u_t @ params["in_proj"]["w"].astype(u_t.dtype)
-    if "b" in params["in_proj"]:
-        z = z + params["in_proj"]["b"].astype(u_t.dtype)
+    z = in_projection(params, u_t)
     w = params["short_filter"]  # (inner, K)
     hist = cache["short"]  # (B, K-1, inner) newest-first
     zc = z.astype(jnp.float32) * w[:, 0].astype(jnp.float32)[None, :]
@@ -157,13 +157,6 @@ def _decode_project(params, cfg, u_t, cache):
     zc = zc.astype(u_t.dtype)
     parts = jnp.split(zc, cfg.order + 1, axis=-1)
     return new_short, parts[0], parts[1:]
-
-
-def _out_project(params, v):
-    y = v @ params["out_proj"]["w"].astype(v.dtype)
-    if "b" in params["out_proj"]:
-        y = y + params["out_proj"]["b"].astype(v.dtype)
-    return y
 
 
 def _newest_first(seq: jax.Array, k: int, L: int, dtype) -> jax.Array:
@@ -245,7 +238,7 @@ def apply_hyena_se(
         # backends (fftconv._fused_epilogue)
         v = (xs[n] * y.astype(x.dtype)).astype(x.dtype)
         v = shard(v, "data", seq_axis, None)
-    return _out_project(params, v)
+    return out_projection(params, v)
 
 
 def init_hyena_se_cache(
@@ -270,9 +263,7 @@ def hyena_se_prefill(
     dtype=jnp.bfloat16,
 ) -> Tuple[jax.Array, dict]:
     B, L, D = x.shape
-    z_pre = x @ params["in_proj"]["w"].astype(x.dtype)
-    if "b" in params["in_proj"]:
-        z_pre = z_pre + params["in_proj"]["b"].astype(x.dtype)
+    z_pre = in_projection(params, x)
     z = short_causal_conv(z_pre, params["short_filter"])
     parts = jnp.split(z, cfg.order + 1, axis=-1)
     v, xs = parts[0], parts[1:]
@@ -284,7 +275,7 @@ def hyena_se_prefill(
         y = _fir_causal_fp32(v, taps[n])
         y = y + v.astype(jnp.float32) * skip[n].astype(jnp.float32)[None, None, :]
         v = (xs[n] * y.astype(x.dtype)).astype(x.dtype)
-    out = _out_project(params, v)
+    out = out_projection(params, v)
     cache = {
         "short": _newest_first(z_pre, cfg.short_filter_len - 1, L, dtype),
         "win": jnp.stack(wins),
@@ -304,7 +295,7 @@ def hyena_se_decode_step(params, cfg: HyenaSEConfig, u_t, cache):
         new_wins.append(_roll_window(cache["win"][n], v))
         conv_y = hist[n] + v.astype(jnp.float32) * h0[n][None, :]
         v = xs[n] * conv_y.astype(u_t.dtype)
-    y = _out_project(params, v)
+    y = out_projection(params, v)
     out_cache = dict(cache)
     out_cache.update({
         "short": new_short,
@@ -371,7 +362,7 @@ def apply_hyena_mr(
         v = shard(v, "data", cp, None) if cp is not None else shard(
             v, "data", None, "model"
         )
-    return _out_project(params, v)
+    return out_projection(params, v)
 
 
 def init_hyena_mr_cache(
@@ -397,9 +388,7 @@ def hyena_mr_prefill(
     backend = get_conv_backend(conv_backend)
     B, L, D = x.shape
     backend.validate_len(L)
-    z_pre = x @ params["in_proj"]["w"].astype(x.dtype)
-    if "b" in params["in_proj"]:
-        z_pre = z_pre + params["in_proj"]["b"].astype(x.dtype)
+    z_pre = in_projection(params, x)
     z = short_causal_conv(z_pre, params["short_filter"])
     parts = jnp.split(z, cfg.order + 1, axis=-1)
     v, xs = parts[0], parts[1:]
@@ -409,7 +398,7 @@ def hyena_mr_prefill(
     for n in range(cfg.order):
         wins.append(_newest_first(v, cfg.support - 1, L, dtype))
         v = backend(v, h[n], skip[n], gate=xs[n]).astype(x.dtype)
-    out = _out_project(params, v)
+    out = out_projection(params, v)
     cache = {
         "short": _newest_first(z_pre, cfg.short_filter_len - 1, L, dtype),
         "win": jnp.stack(wins),
@@ -436,7 +425,7 @@ def hyena_mr_decode_step(params, cfg: HyenaMRConfig, u_t, cache):
         new_wins.append(_roll_window(cache["win"][n], v))
         conv_y = hist[n] + v.astype(jnp.float32) * h0[n][None, :]
         v = xs[n] * conv_y.astype(u_t.dtype)
-    y = _out_project(params, v)
+    y = out_projection(params, v)
     out_cache = dict(cache)
     out_cache.update({
         "short": new_short,

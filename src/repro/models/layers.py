@@ -1,4 +1,9 @@
-"""Shared layers: norms, channel-MLP variants, embeddings, RoPE."""
+"""Shared layers: norms, channel-MLP variants, embeddings, RoPE.
+
+Each layer runs under a ``jax.named_scope`` (``rms_norm``, ``mlp``,
+``token_embed``): the name lands in its ops' HLO metadata, which a
+profiler trace shows as the op's ``tf_op`` path.
+"""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
@@ -22,6 +27,7 @@ def init_norm(d: int, kind: str = "rmsnorm") -> Dict[str, Any]:
     raise ValueError(kind)
 
 
+@jax.named_scope("rms_norm")
 def apply_norm(params, x, kind: str = "rmsnorm", eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":
@@ -70,6 +76,7 @@ def init_mlp(key, d_model: int, d_ff: int, kind: str = "swiglu") -> Dict[str, An
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params, x, kind: str = "swiglu"):
     from repro.distributed.ctx import shard
 
@@ -98,10 +105,12 @@ def init_embedding(key, vocab: int, d_model: int):
     }
 
 
+@jax.named_scope("token_embed")
 def embed(params, tokens, dtype=jnp.bfloat16):
     return params["table"].astype(dtype)[tokens]
 
 
+@jax.named_scope("token_embed")
 def unembed(params, x):
     return x @ params["table"].astype(x.dtype).T
 

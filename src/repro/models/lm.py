@@ -83,6 +83,14 @@ def init_lm(key, cfg: ModelConfig) -> Dict[str, Any]:
     return params
 
 
+def _head(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """Logits: the embedding's transpose when tied, else the head matmul."""
+    if cfg.tie_embeddings:
+        return unembed(params["embed"], x)
+    with jax.named_scope("lm_head"):
+        return x @ params["head"]["w"].astype(x.dtype)
+
+
 def forward(
     params,
     cfg: ModelConfig,
@@ -172,10 +180,7 @@ def forward(
         for k, v in taux.items():
             aux[k] = aux[k] + v
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = unembed(params["embed"], x)
-    else:
-        logits = x @ params["head"]["w"].astype(x.dtype)
+    logits = _head(params, cfg, x)
     # sequence-sharded logits: full-vocab rows live on one chip, so the loss
     # never materializes a vocab-sharded softmax nor a full (B, L, V) fp32.
     # (Under cp the loss reductions over the sharded L dim are plain jnp
@@ -203,15 +208,17 @@ def loss_fn(
         params, cfg, tokens, frontend_embeds,
         ctx=ctx or TRAIN_CONTEXT, compute_dtype=compute_dtype,
     )
-    logits = logits.astype(jnp.float32)
-    mask = (labels != IGNORE).astype(jnp.float32)
-    safe_labels = jnp.where(labels == IGNORE, 0, labels)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, safe_labels[..., None], axis=-1)[..., 0]
-    nll = (logz - ll) * mask
-    denom = jnp.maximum(jnp.sum(mask), 1.0)
-    loss = jnp.sum(nll) / denom
-    zl = jnp.sum(jnp.square(logz) * mask) / denom
+    with jax.named_scope("lm_head"):
+        logits = logits.astype(jnp.float32)
+        mask = (labels != IGNORE).astype(jnp.float32)
+        safe_labels = jnp.where(labels == IGNORE, 0, labels)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(
+            logits, safe_labels[..., None], axis=-1)[..., 0]
+        nll = (logz - ll) * mask
+        denom = jnp.maximum(jnp.sum(mask), 1.0)
+        loss = jnp.sum(nll) / denom
+        zl = jnp.sum(jnp.square(logz) * mask) / denom
     total = loss + z_loss_weight * zl
     if cfg.moe:
         total = total + moe_aux_weight * (
@@ -277,10 +284,7 @@ def prefill(
             tail_caches.append(c)
         caches["tail"] = tail_caches
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = unembed(params["embed"], x)
-    else:
-        logits = x @ params["head"]["w"].astype(x.dtype)
+    logits = _head(params, cfg, x)
     return logits.astype(jnp.float32), caches
 
 
@@ -537,9 +541,6 @@ def decode_step(
             new_tail.append(c)
         out_caches["tail"] = new_tail
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = unembed(params["embed"], x)
-    else:
-        logits = x @ params["head"]["w"].astype(x.dtype)
+    logits = _head(params, cfg, x)
     logits = shard(logits, "data", "model")
     return logits.astype(jnp.float32), out_caches

@@ -21,7 +21,11 @@ step/checkpoint/telemetry lifecycle.  The loop owns, on the shared
     in-flight step, writes a final committed checkpoint at the step
     boundary, and returns ``status="preempted"``.
   * **Telemetry** — straggler EWMA, heartbeat liveness file, per-step
-    ``on_step`` hook, periodic logging.
+    ``on_step`` hook, periodic logging.  Dispatch returns before the
+    device has run a step, so steps are timed only over intervals that
+    end in a host sync (``_StepClock``); the profiler sees the loop's
+    ``repro.train.data``, ``repro.train.sync`` and
+    ``repro.train.checkpoint`` spans on the device's clock.
 
 Data sources are either a *stateless* callable ``(step, rng) -> batch``
 (synthetic tasks: resume needs only the step and the checkpointed base
@@ -50,6 +54,31 @@ from repro.train.trainer import (
     init_train_state,
     jit_train_step,
 )
+
+
+def _span(name: str):
+    return jax.profiler.TraceAnnotation(f"repro.train.{name}")
+
+
+class _StepClock:
+    """Steps and tokens since the last lap, and the seconds they took.
+    A lap is taken right after a host sync, so the interval holds the
+    device's work for those steps and not only their dispatch."""
+
+    def __init__(self):
+        self.start = time.perf_counter()
+        self.steps = self.tokens = 0
+
+    def add(self, tokens: int) -> None:
+        self.steps += 1
+        self.tokens += tokens
+
+    def lap(self):
+        """(steps, seconds, tokens) since the last lap; starts the next."""
+        now = time.perf_counter()
+        out = (self.steps, now - self.start, self.tokens)
+        self.start, self.steps, self.tokens = now, 0, 0
+        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,7 +229,9 @@ class TrainLoop:
         stream (see module docstring).  ``key`` seeds a fresh run; once a
         checkpoint exists the checkpointed base key wins, so restarts never
         fork the trajectory.  ``on_step(step, metrics, seconds)`` fires
-        after every step (telemetry hook; step counts completed steps).
+        after every step (telemetry hook; step counts completed steps);
+        reading ``metrics`` to the host syncs, so ``seconds`` is the
+        step's time from the previous sync to this one.
         """
         lcfg = self.lcfg
         if key is None:
@@ -227,13 +258,23 @@ class TrainLoop:
                 )
                 heartbeat.start()
 
+        steps_clock, log_clock = _StepClock(), _StepClock()
+
         def save(step: int):
             if writer is not None:
-                writer.save(
-                    step,
-                    {"train": state, "rng": base_key},
-                    meta={"step": step, "loader": loader_meta()},
-                )
+                with _span("checkpoint"):
+                    writer.save(
+                        step,
+                        {"train": state, "rng": base_key},
+                        meta={"step": step, "loader": loader_meta()},
+                    )
+                steps_clock.lap()  # the save is not a step's time
+
+        def synced(step: int) -> bool:
+            """After a host sync: the steps since the last one feed the
+            straggler monitor their mean time; True if they were slow."""
+            n, secs, _ = steps_clock.lap()
+            return n > 0 and self.monitor.record(step, secs / n)
 
         # per-step losses stay device-side between boundaries so the host
         # never blocks on step i before dispatching step i+1; they flush
@@ -244,31 +285,39 @@ class TrainLoop:
         metrics: Dict[str, Any] = {}
         to_host = lambda m: {k: float(v) for k, v in m.items()}
 
-        def flush_history():
+        def flush_history(step: int) -> bool:
             if pending:
-                history.extend(
-                    float(x) for x in jax.device_get(list(pending))
-                )
+                with _span("sync"):
+                    got = jax.device_get(list(pending))
+                history.extend(float(x) for x in got)
                 pending.clear()
+            return synced(step)
 
         status = "done"
         last_saved = -1
+        slow = False
         try:
             with self.ectx.scope():
                 for i in range(start, lcfg.total_steps):
-                    t0 = time.time()
-                    batch = self._place_batch(
-                        fetch(i, jax.random.fold_in(base_key, i))
-                    )
+                    with _span("data"):
+                        batch = self._place_batch(
+                            fetch(i, jax.random.fold_in(base_key, i))
+                        )
                     state, metrics = self._step_fn(state, batch)
                     pending.append(metrics["loss"])
-                    dt = time.time() - t0
-                    slow = self.monitor.record(i, dt)
+                    tokens = batch["tokens"].size if "tokens" in batch else 0
+                    steps_clock.add(tokens)
+                    log_clock.add(tokens)
                     done = i + 1
                     if on_step is not None:
-                        on_step(done, to_host(metrics), dt)
+                        with _span("sync"):
+                            host = to_host(metrics)
+                        dt = steps_clock.lap()[1]
+                        slow = self.monitor.record(done, dt) or slow
+                        on_step(done, host, dt)
+                        steps_clock.lap()  # the hook's time is no step's
                     if done % lcfg.ckpt_every == 0 and done < lcfg.total_steps:
-                        flush_history()
+                        slow = flush_history(done) or slow
                         save(done)
                         last_saved = done
                     if handler.preempted():
@@ -282,23 +331,24 @@ class TrainLoop:
                         self.log(
                             f"preempted — committed step {done}, exiting"
                         )
-                        flush_history()
+                        flush_history(done)
                         return LoopResult(
                             status, state, done, history,
                             to_host(metrics), self.monitor.stragglers,
                         )
                     if done % lcfg.log_every == 0 or done == lcfg.total_steps:
-                        flush_history()
-                        tok = batch["tokens"].size if "tokens" in batch else 0
+                        slow = flush_history(done) or slow
+                        _, secs, tok = log_clock.lap()
                         self.log(
                             f"step {done:5d} loss {history[-1]:.3f} "
                             f"gnorm {float(metrics.get('grad_norm', 0.0)):.2f} "
-                            f"{tok / dt:.0f} tok/s"
+                            f"{tok / secs:.0f} tok/s"
                             + (" [straggler]" if slow else "")
                         )
+                        slow = False
             if last_saved != lcfg.total_steps:
                 save(lcfg.total_steps)
-            flush_history()
+            flush_history(lcfg.total_steps)
             return LoopResult(
                 status, state, lcfg.total_steps, history,
                 to_host(metrics) if metrics else {},

@@ -65,6 +65,7 @@ def global_norm(tree) -> jax.Array:
     )
 
 
+@jax.named_scope("optimizer")
 def adamw_update(
     cfg: AdamWConfig, grads, opt_state, params
 ) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
