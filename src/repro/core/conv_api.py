@@ -256,14 +256,15 @@ def _fft_sp(u, h, skip=None, gate=None):
 register_conv_backend(ConvBackend(
     name="fft", tag="shard_map_fft", fn=_fft, mesh_aware=True,
     supports_gate=True,
-    description="O(L log L) real FFT on fast-composite >= 2L-1 points; "
-    "shard_map-forced per-chip execution under a mesh, plain XLA FFT "
-    "otherwise; gate+skip fused into the post-iFFT elementwise pass.",
+    description="O(L log L) DFT conv on fast-composite >= 2L-1 points: "
+    "XLA's real FFT off TPU, the MXU four-step DFT (blockfft) on TPU; "
+    "shard_map-forced per-chip execution under a mesh; gate+skip fused "
+    "into the post-inverse elementwise pass.",
 ))
 register_conv_backend(ConvBackend(
     name="fft_local", tag="xla_fft", fn=_fft_local, supports_gate=True,
-    description="single-device XLA FFT path (no shard_map), used as the "
-    "oracle for the sharded variant.",
+    description="the fft backend's single-device path (no shard_map), "
+    "used as the oracle for the sharded variant.",
 ))
 register_conv_backend(ConvBackend(
     name="direct", tag="toeplitz_oracle", fn=_direct, oracle=True,
@@ -273,9 +274,10 @@ register_conv_backend(ConvBackend(
 ))
 register_conv_backend(ConvBackend(
     name="blockfft", tag="matmul_dft", fn=_blockfft, supports_gate=True,
-    description="four-step (Bailey) FFT with the small DFTs as dense "
-    "matmuls — every FLOP on the MXU (H3-style block FFT); factor split "
-    "autotunable (core.autotune).",
+    description="four-step (Bailey) DFT with the small DFTs as "
+    "fp32-exact (HIGHEST) real matmuls on the MXU and its own VJP — the "
+    "fft backend's transform on TPU, here on every platform; factor "
+    "split autotunable (core.autotune).",
 ))
 register_conv_backend(ConvBackend(
     name="blockfft_overlap", tag="twolevel_overlap", fn=_blockfft_overlap,

@@ -8,8 +8,12 @@ because the filter is evaluated at ``t = 0..L-1`` only and the padding
 prevents circular wrap-around (paper: "all we need is to evaluate the filter
 at t=0,…,L−1 and zero-pad ... to 2L−1 before taking FFT").
 
-FFT always runs in fp32 (bf16 FFT loses too much precision over long
-reductions); inputs/outputs keep their dtype.
+The transform always runs in fp32 (bf16 loses too much precision over
+long reductions); inputs/outputs keep their dtype.  Which transform runs
+follows the platform, read when the conv is traced: XLA's real FFT on the
+CPU and GPU; on a TPU the four-step DFT as fp32-exact (``HIGHEST``) MXU
+matmuls, with its own VJP (``core/blockfft.py``), because XLA's TPU FFT
+moves the transform axis minor and back through HBM many times.
 
 The optional ``gate`` argument fuses the Hyena recurrence's data-controlled
 gate ``xⁿ ⊙ conv(v)`` into the single post-iFFT elementwise expression —
@@ -24,6 +28,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.platform import on_tpu
 
 
 def next_fast_len(n: int) -> int:
@@ -74,7 +80,17 @@ def fft_causal_conv(
     gate: Optional[jax.Array] = None,  # (B, L, D) elementwise output gate
 ) -> jax.Array:
     """Depthwise causal convolution of every channel with its own length-L
-    filter, via real FFT on ``next_fast_len(2L - 1)`` points."""
+    filter, via a DFT on ``next_fast_len(2L - 1)`` points.
+
+    The transform follows the platform the program runs on, read when the
+    conv is traced: on a TPU the four-step DFT on the MXU
+    (``blockfft.blockfft_causal_conv``, fp32-exact matmuls), because
+    XLA's TPU FFT moves the transform axis minor and back through HBM many
+    times; elsewhere XLA's real FFT.  Both compute the same conv in fp32."""
+    if on_tpu():
+        from repro.core.blockfft import blockfft_causal_conv
+
+        return blockfft_causal_conv(u, h, skip, gate)
     B, L, D = u.shape
     assert h.shape == (D, L), (h.shape, (D, L))
     # linear conv of two length-L signals has support 2L-1; any fft_size

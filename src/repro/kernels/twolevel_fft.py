@@ -43,11 +43,42 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blockfft import _dft_mats, _factor, _four_step_fft
+from repro.core.blockfft import _factor
 from repro.kernels.platform import resolve_interpret
+
+
+@functools.lru_cache(maxsize=32)
+def _dft_mats(N: int, factors: Optional[Tuple[int, int]] = None):
+    """Complex64 (R×R, S×S DFTs, R×S twiddle) of the split."""
+    # numpy on purpose: this cache is shared across jit traces, and jnp
+    # constant construction inside a trace would poison it with tracers
+    # from a long-dead trace (UnexpectedTracerError on the next jit).
+    R, S = _factor(N) if factors is None else factors
+    if R * S != N:
+        raise ValueError(f"factors {factors} do not multiply to N={N}")
+    r = np.arange(R)
+    s = np.arange(S)
+    FR = np.exp(-2j * np.pi * np.outer(r, r) / R).astype(np.complex64)
+    FS = np.exp(-2j * np.pi * np.outer(s, s) / S).astype(np.complex64)
+    TW = np.exp(
+        -2j * np.pi * np.outer(r, s) / N
+    ).astype(np.complex64)  # W_N^{k1·s}
+    return R, S, FR, FS, TW
+
+
+def _four_step_fft(x: jax.Array, N: int, factors) -> jax.Array:
+    """x: (B, N, D) real -> spectrum C (B, R, S, D) with
+    X[k1 + k2·R] = C[:, k1, k2, :] (the kernel's filter spectrum)."""
+    R, S, FR, FS, TW = _dft_mats(N, factors)
+    B, _, D = x.shape
+    A = x.reshape(B, R, S, D).astype(jnp.complex64)
+    Bm = jnp.einsum("kr,brsd->bksd", FR, A)
+    Bm = Bm * TW[None, :, :, None]
+    return jnp.einsum("bksd,sj->bkjd", Bm, FS)
 
 
 def _largest_divisor_leq(n: int, k: int) -> int:

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.blockfft import blockfft_causal_conv, _factor
+from repro.core.blockfft import _factor, _split, blockfft_causal_conv
 from repro.core.fftconv import fft_causal_conv
 
 
@@ -39,10 +39,76 @@ def test_blockfft_in_hyena_mixer():
     np.testing.assert_allclose(y_fft, y_bl, rtol=2e-3, atol=2e-3)
 
 
+def _conv_and_grads(conv, u, h, skip, gate, dy):
+    """The conv's output and its gradients in u, h, skip (and gate), as
+    float32 numpy, from one jitted ``value_and_grad``."""
+    argnums = (0, 1, 2) if gate is None else (0, 1, 2, 3)
+
+    def loss(u, h, skip, gate):
+        y = conv(u, h, skip, gate)
+        return jnp.vdot(y.astype(jnp.float32), dy), y
+
+    (_, y), grads = jax.jit(
+        jax.value_and_grad(loss, argnums=argnums, has_aux=True)
+    )(u, h, skip, gate)
+    return [np.asarray(x, np.float32) for x in (y, *grads)]
+
+
+# power of two, 5-smooth (padded N = 90 = 2·3²·5), prime (N = 75); the
+# last case runs under an ambient bf16 matmul precision
+_MXU_CASES = [
+    (L, dtype, gated, None)
+    for L in (64, 45, 37)
+    for dtype in ("float32", "bfloat16")
+    for gated in (False, True)
+] + [(37, "float32", True, "bfloat16")]
+
+
+@pytest.mark.parametrize("L,dtype,gated,precision", _MXU_CASES)
+def test_blockfft_matches_direct_values_and_grads(L, dtype, gated,
+                                                  precision):
+    """The MXU four-step conv (the ``fft`` backend's transform on a TPU)
+    against the O(L²) oracle, in values and in ``jax.grad`` w.r.t. u, h,
+    skip and gate.  Its matmuls are pinned to HIGHEST, so an ambient
+    ``default_matmul_precision("bfloat16")`` must not move it off the
+    float32 tolerance.  XLA:CPU computes float32 dots in full at any
+    precision, so that case cannot fail here; the lowering test in
+    ``test_chip_compile.py`` checks what the chip is given."""
+    from repro.core.fftconv import direct_causal_conv
+
+    dt = getattr(jnp, dtype)
+    rng = np.random.default_rng(L)
+    B, D = 2, 5
+    u = jnp.asarray(rng.standard_normal((B, L, D)), dt)
+    h = jnp.asarray(rng.standard_normal((D, L)) / np.sqrt(L), dt)
+    skip = jnp.asarray(rng.standard_normal((D,)), dt)
+    gate = jnp.asarray(rng.standard_normal((B, L, D)), dt) if gated else None
+    dy = jnp.asarray(rng.standard_normal((B, L, D)), jnp.float32)
+    with jax.default_matmul_precision(precision or "highest"):
+        got = _conv_and_grads(blockfft_causal_conv, u, h, skip, gate, dy)
+    # the oracle runs on the same values in float32: in bf16 its own
+    # gather-transpose would sum dh's contributions in bf16
+    f32 = [None if x is None else x.astype(jnp.float32)
+           for x in (u, h, skip, gate)]
+    with jax.default_matmul_precision("highest"):
+        want = _conv_and_grads(direct_causal_conv, *f32, dy)
+    # float32: normwise relative error near fp32 rounding; bf16: the
+    # program rounds to bf16 up to three times on the way (the cotangent,
+    # the gated product, the result), 2^-8 relative each
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -6
+    for name, g, w in zip(("y", "du", "dh", "dskip", "dgate"), got, want):
+        err = np.max(np.abs(g - w)) / np.max(np.abs(w))
+        assert err < tol, (name, err)
+
+
 def test_factorization():
-    for N in [16, 64, 1024, 65536]:
-        R, S = _factor(N)
-        assert R * S == N and R >= S
+    for split in (_factor, _split):
+        for N in [16, 64, 1024, 65536]:
+            R, S = split(N)
+            assert R * S == N and R >= S
+    # the MXU path's rule: the least matmul work, measured fastest on a
+    # v5e among the splits of 4096 (the hyena-153m train step's N)
+    assert _split(4096) == (128, 32)
 
 
 def test_vit_forward_and_grad():

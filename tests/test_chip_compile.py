@@ -91,3 +91,61 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*args).compile()
     # the Pallas body really lowered to a Mosaic kernel (no XLA fallback)
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def _mlir_ops(module, name):
+    """Every operation called ``name`` in an MLIR module, nested ones too."""
+    found = []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    if inner.operation.name == name:
+                        found.append(inner.operation)
+                    walk(inner.operation)
+
+    walk(module.operation)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_long_conv_lowers_to_highest_dft_matmuls_for_v5e(one_chip, dtype,
+                                                          monkeypatch):
+    """The default ``fft`` backend's gated conv under remat, value and
+    gradients, at hyena-153m widths.  Traced for a TPU and lowered for the
+    described chip, its forward, recomputed forward and backward
+    transforms are the four-step DFT's matmuls, each at HIGHEST even under
+    an ambient bf16 matmul precision, with no XLA FFT op; traced for this
+    CPU it is XLA's FFT.  Lowering only: nothing is compiled."""
+    from repro.core import fftconv
+    from repro.core.blockfft import SCOPE
+    from repro.core.conv_api import get_conv_backend
+
+    conv = get_conv_backend("fft")
+    dt = getattr(jnp, dtype)
+    shapes = [(B, L, D), (D, L), (D,), (B, L, D)]
+
+    def lower(specs):  # a fresh function each time: traced anew
+        def loss(u, h, skip, gate):
+            return jnp.sum(conv(u, h, skip, gate).astype(jnp.float32))
+
+        return jax.jit(jax.value_and_grad(
+            jax.checkpoint(loss), argnums=(0, 1, 2, 3))).lower(*specs)
+
+    cpu = lower([jax.ShapeDtypeStruct(s, dt) for s in shapes])
+    assert "stablehlo.fft" in cpu.as_text()
+    # the conv reads the platform when it is traced; this process's is
+    # the CPU, so the test stands in the chip's answer
+    monkeypatch.setattr(fftconv, "on_tpu", lambda: True)
+    with jax.default_matmul_precision("bfloat16"):
+        tpu = lower([_spec(one_chip, s, dt) for s in shapes])
+    assert "stablehlo.fft" not in tpu.as_text()
+    dots = _mlir_ops(tpu.compiler_ir("stablehlo"), "stablehlo.dot_general")
+    # two stages each for u's and h's spectra and the inverse, in the
+    # forward and again in the recomputed forward; in the backward, for
+    # dc's spectrum, du's inverse and dh's inverse
+    assert len(dots) == 18, len(dots)
+    for op in dots:
+        assert SCOPE in str(op.location), str(op.location)
+        assert "HIGHEST" in str(op.attributes["precision_config"]), op
