@@ -101,6 +101,28 @@ def test_blockfft_matches_direct_values_and_grads(L, dtype, gated,
         assert err < tol, (name, err)
 
 
+@pytest.mark.parametrize("L", [2048, 8192])
+def test_blockfft_matches_fft_at_benchmark_lengths(L):
+    """The MXU four-step conv against XLA's FFT conv at the benchmark's
+    training lengths: L = 2048 (N = 4096, split (128, 32)) and L = 8192
+    (N = 16384, split (256, 64)), in values and in the gradients w.r.t. u,
+    h, skip and gate.  1e-5 normwise is float32 rounding with room: both
+    sides compute in float32, and the errors read near 6e-7."""
+    rng = np.random.default_rng(L)
+    B, D = 1, 3
+    u = jnp.asarray(rng.standard_normal((B, L, D)), jnp.float32)
+    h = jnp.asarray(rng.standard_normal((D, L)) / np.sqrt(L), jnp.float32)
+    skip = jnp.asarray(rng.standard_normal((D,)), jnp.float32)
+    gate = jnp.asarray(rng.standard_normal((B, L, D)), jnp.float32)
+    dy = jnp.asarray(rng.standard_normal((B, L, D)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = _conv_and_grads(blockfft_causal_conv, u, h, skip, gate, dy)
+        want = _conv_and_grads(fft_causal_conv, u, h, skip, gate, dy)
+    for name, g, w in zip(("y", "du", "dh", "dskip", "dgate"), got, want):
+        err = np.max(np.abs(g - w)) / np.max(np.abs(w))
+        assert err < 1e-5, (name, err)
+
+
 def test_factorization():
     for split in (_factor, _split):
         for N in [16, 64, 1024, 65536]:
@@ -109,6 +131,8 @@ def test_factorization():
     # the MXU path's rule: the least matmul work, measured fastest on a
     # v5e among the splits of 4096 (the hyena-153m train step's N)
     assert _split(4096) == (128, 32)
+    # the hyena-355m train step's N (L = 8192)
+    assert _split(16384) == (256, 64)
 
 
 def test_vit_forward_and_grad():
